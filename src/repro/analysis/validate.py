@@ -136,10 +136,12 @@ def structural_error(pass_name: str, code: str, message: str,
                      **ids) -> AnalysisError:
     """A single-finding error report for structural build failures (bad
     placement dicts, unknown targets) — same rendering as the validator
-    passes, so every compile diagnostic speaks one format."""
+    passes, so every compile diagnostic speaks one format. Jitted code
+    may raise it at trace time, from static shapes only."""
     r = AnalysisReport()
-    r.add("error", code, pass_name, message,
-          ids={k: np.atleast_1d(v) for k, v in ids.items()})
+    ids = {k: np.atleast_1d(v)  # tracelint: allow=numpy-call
+           for k, v in ids.items()}  # tracelint: allow=dict-iter
+    r.add("error", code, pass_name, message, ids=ids)
     return AnalysisError(r)
 
 

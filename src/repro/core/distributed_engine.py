@@ -38,7 +38,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import neuron as nrn
@@ -150,7 +149,7 @@ def make_snn_step(cfg: SNNShardConfig, mesh):
             V_next = nrn.integrate_phase(V_mid, syn)
             return V_next, spikes
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(all_axes), P(all_axes), P(all_axes), P(all_axes),
                       P(None, all_axes), P()),

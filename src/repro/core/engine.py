@@ -67,6 +67,8 @@ class EventEngine:
         # executable keeps its original path).
         self.vectorized = vectorized
         self.use_pallas = use_pallas
+        if use_pallas:
+            route_k.check_fused_width(n_neurons)
         self._spikes = np.zeros((n_neurons,), bool)
         # numpy views of the table for the host-side reference routing
         self._post = np.asarray(image.syn_post)

@@ -4,7 +4,7 @@ device mesh (`CRI_network(..., backend="mesh")`).
 The hiaer tier (core.hiaer) already structures every timestep as
 per-core blocks plus a level-by-level spike exchange, but folds all of
 it onto one device. Here the same per-core data model runs under
-`compat.shard_map` over a 1-D mesh of D devices, each owning
+`jax.shard_map` over a 1-D mesh of D devices, each owning
 C // D consecutive cores:
 
   * per-core STATE is sharded: membranes, model tables, and — the part
@@ -28,10 +28,9 @@ C // D consecutive cores:
     and the replicated event-vector floor both drop ~32x, the
     address-event-bits wire of the paper's fabric;
   * `run_batch` folds the sample batch INTO the device-local state
-    inside shard_map (rank-stable on jax 0.4.x, unlike
-    vmap-of-shard_map): B samples share one collective per hierarchy
-    level per timestep, recovering the monolithic engine's batched
-    throughput at mesh scale;
+    inside shard_map (rank-stable, unlike vmap-of-shard_map): B samples
+    share one collective per hierarchy level per timestep, recovering
+    the monolithic engine's batched throughput at mesh scale;
   * phase 2 is the same scatter-free ragged segment sum as hiaer, run on
     the device-local entries with device-rebased CSR offsets.
 
@@ -60,7 +59,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import make_mesh, shard_map
 from repro.core import hbm
 from repro.core import neuron as nrn
 from repro.core import schedule as sched
@@ -150,9 +148,9 @@ class MeshNetwork:
                              f"{len(jax.devices())} available devices")
         self.n_devices = n_devices
         self.cores_per_device = C // n_devices
-        self.mesh = make_mesh((n_devices,), (AXIS,),
-                              devices=np.asarray(
-                                  jax.devices()[:n_devices]))
+        self.mesh = jax.make_mesh(
+            (n_devices,), (AXIS,), devices=jax.devices()[:n_devices],
+            axis_types=(jax.sharding.AxisType.Auto,))
         self._stages = exch_k.collective_stages(self.spec, n_devices)
         self._shard = NamedSharding(self.mesh, P(AXIS))
         self._repl = NamedSharding(self.mesh, P())
@@ -221,16 +219,16 @@ class MeshNetwork:
         self._spikes = np.zeros((n_neurons,), bool)
 
         table_specs = MeshTables(*([P(AXIS)] * 8 + [P()] * 9))
-        self._smapped = shard_map(
+        self._smapped = jax.shard_map(
             self._device_step, mesh=self.mesh,
             in_specs=(P(AXIS), P(), P(), table_specs),
             out_specs=(P(AXIS), P()), check_vma=False)
         # the batched step: B samples folded into the device-local state
         # (leading axis of Vc is the batch, the CORE axis stays the
-        # sharded one) — rank-stable on jax 0.4.x, unlike
+        # sharded one) — rank-stable, unlike
         # vmap-of-shard_map, and all B samples share one collective per
         # hierarchy level per timestep.
-        self._smapped_batch = shard_map(
+        self._smapped_batch = jax.shard_map(
             self._device_step, mesh=self.mesh,
             in_specs=(P(None, AXIS), P(), P(), table_specs),
             out_specs=(P(None, AXIS), P()), check_vma=False)
@@ -399,7 +397,7 @@ class MeshNetwork:
         (B, T, A) int32. Sample b runs from V = 0 under stream
         fold_in(key, b) — identical to EventEngine.run_batch. The batch
         axis is FOLDED into the device-local state arrays inside
-        shard_map (`_smapped_batch`; rank-stable on jax 0.4.x, unlike
+        shard_map (`_smapped_batch`; rank-stable, unlike
         vmap-of-shard_map), so the scan is over T only and all B
         samples share one grouped all_gather per hierarchy level per
         timestep instead of B of them. Output-identical to the retired

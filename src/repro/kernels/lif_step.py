@@ -16,36 +16,28 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK = 256
+from repro.core import neuron as nrn
+from repro.kernels.backend import resolve_interpret
+
+# XLA tiles a 1-D int32 array in 1024-element tiles on TPU; a block of
+# any other length is refused by Mosaic as a layout mismatch
+BLOCK = 1024
 
 
 def _kernel(V_ref, syn_ref, u_ref, theta_ref, nu_ref, lam_ref, lif_ref,
             Vout_ref, spike_ref):
-    V = V_ref[...]
-    u = u_ref[...] | 1
-    nu = nu_ref[...]
-    pos = jnp.minimum(jnp.maximum(nu, 0), 31)
-    neg = jnp.minimum(jnp.maximum(-nu, 0), 31)
-    mag = jnp.abs(u) >> neg
-    xi = jnp.where(nu >= 0, u << pos, jnp.sign(u) * mag)
-    V = V + xi
-    spikes = V > theta_ref[...]
-    V = jnp.where(spikes, 0, V)
-    lam = lam_ref[...]
-    pow2 = jnp.int32(1) << jnp.minimum(lam, 30)
-    leaked = V - jnp.where(lam >= 31, V >> 31, V // pow2)
-    V = jnp.where(lif_ref[...] != 0, leaked, 0)
+    V, spikes = nrn.fire_phase_from_u(
+        V_ref[...], theta_ref[...], nu_ref[...], lam_ref[...],
+        lif_ref[...] != 0, u_ref[...])
     Vout_ref[...] = V + syn_ref[...]
     spike_ref[...] = spikes.astype(jnp.int32)
 
 
 def lif_step(V, syn_in, noise_u, theta, nu, lam, is_lif, *, interpret=None):
     """All inputs (N,) int32 (is_lif: bool). Returns (V_next, spikes_bool).
-    N must be a multiple of 256 (pad the membrane file)."""
+    N must be a multiple of BLOCK (pad the membrane file)."""
     n = V.shape[0]
     assert n % BLOCK == 0, n
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     spec = pl.BlockSpec((BLOCK,), lambda i: (i,))
     V_next, spikes = pl.pallas_call(
         _kernel,
@@ -54,6 +46,6 @@ def lif_step(V, syn_in, noise_u, theta, nu, lam, is_lif, *, interpret=None):
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32),
                    jax.ShapeDtypeStruct((n,), jnp.int32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(V, syn_in, noise_u, theta, nu, lam, is_lif.astype(jnp.int32))
     return V_next, spikes.astype(bool)

@@ -1,7 +1,8 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-`interpret` resolves automatically: Python-interpret on CPU (correctness /
-CI), compiled Mosaic on TPU. Padding to hardware tile boundaries (128-lane,
+`interpret=None` is resolved by `kernels.backend.resolve_interpret`:
+compiled Mosaic on TPU, the Pallas interpreter on the CPU backend the tests
+use, an error anywhere else. Padding to hardware tile boundaries (128-lane,
 the segment-alignment analogue) happens here so callers keep natural sizes.
 """
 from __future__ import annotations

@@ -141,8 +141,6 @@ def lower_cell(arch_id: str, shape_name: str, multi_pod: bool,
     text = compiled.as_text()
     an = hlo_analysis.analyze(text)
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # jax 0.4.x: one dict per program
-        ca = ca[0] if ca else {}
     try:
         mem = compiled.memory_analysis()
         memd = {k: int(getattr(mem, k)) for k in

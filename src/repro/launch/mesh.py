@@ -12,17 +12,20 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh
 
-from repro.compat import make_mesh
+
+def _auto_mesh(shape, axes) -> Mesh:
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh() -> Mesh:
     """Whatever devices exist, as a (1, n) ('data','model') mesh — used by
     smoke tests and the single-host examples."""
     n = len(jax.devices())
-    return make_mesh((1, n), ("data", "model"))
+    return _auto_mesh((1, n), ("data", "model"))

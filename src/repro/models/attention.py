@@ -168,7 +168,6 @@ def _shardmap_seq_attention(q, k, v, cfg, window):
     FLOPs + a full-seq all-gather of q). Here the query axis is explicitly
     shard_map'd over 'model': each device all-gathers the (small, GQA) K/V
     once and computes only its S/16 query block."""
-    from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed.context import batch_axes, get_mesh
     mesh = get_mesh()
@@ -183,8 +182,8 @@ def _shardmap_seq_attention(q, k, v, cfg, window):
         return attend(qb, kf, vf, cfg, q_start=start, kv_len=kf.shape[1],
                       window=window, q_chunk=min(1024, S_loc))
 
-    return shard_map(f, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(f, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def _seq_shard(t, axis=1):

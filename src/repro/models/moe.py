@@ -24,7 +24,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.context import batch_axes, get_mesh, tp_axis
@@ -185,8 +184,8 @@ def moe_apply(p, x, cfg, decode=False):
             aux = jax.lax.pmean(aux, baxes + (tp_axis(),))
             return y, aux
 
-    fn = shard_map(f, mesh=mesh, in_specs=(expert_specs, x_spec),
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(f, mesh=mesh, in_specs=(expert_specs, x_spec),
+                       out_specs=out_specs, check_vma=False)
     return fn(p, x)
 
 
@@ -266,8 +265,8 @@ def _decode_moe_2d(p, x, cfg):
         aux = jax.lax.pmean(aux, baxes + (tp_axis(),))
         return y, aux
 
-    fn = shard_map(f, mesh=mesh, in_specs=(especs, x_spec),
-                   out_specs=(P(baxes, None, None), P()), check_vma=False)
+    fn = jax.shard_map(f, mesh=mesh, in_specs=(especs, x_spec),
+                       out_specs=(P(baxes, None, None), P()), check_vma=False)
     return fn(p, x)
 
 

@@ -120,11 +120,13 @@ def main(argv=None) -> int:
         if args.faults_log:
             os.environ["REPRO_FAULTS_LOG"] = args.faults_log
     faults.install_from_env()
+    from repro.compile_cache import use_compile_cache
     from repro.portal.gateway import Portal
     from repro.serve import SpikeServer
     from repro.serve.__main__ import demo_spec
     from repro.core.compile import compile_spec
 
+    use_compile_cache()
     compiled = compile_spec(demo_spec(args.axons, args.neurons),
                             target=args.backend)
     tel = Telemetry(on=not args.no_telemetry, log_json=args.log_json)

@@ -103,7 +103,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from repro import faults
+    from repro.compile_cache import use_compile_cache
     from repro.obs import Telemetry, chrome_trace
+
+    use_compile_cache()
 
     if args.faults:
         faults.install(faults.FaultPlan.from_spec(

@@ -21,9 +21,9 @@ from repro.launch.sharding import ShardingRules, to_named
 from repro.models import lm
 
 cfg = get_reduced("gemma_7b")
-from repro.compat import make_mesh
-mesh_a = make_mesh((2, 4), ("data", "model"))
-mesh_b = make_mesh((4, 2), ("data", "model"))
+auto = (jax.sharding.AxisType.Auto,) * 2
+mesh_a = jax.make_mesh((2, 4), ("data", "model"), axis_types=auto)
+mesh_b = jax.make_mesh((4, 2), ("data", "model"), axis_types=auto)
 
 with mesh_context(mesh_a):
     params = lm.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)

@@ -7,6 +7,7 @@ import pytest
 from _hyp import given, settings, st
 
 from repro.kernels import ops, ref
+from repro.kernels.backend import resolve_interpret
 
 
 # ------------------------------------------------------------ spike matmul
@@ -33,10 +34,36 @@ def test_spike_matmul_unaligned_padding():
         np.asarray(got), np.asarray(ref.spike_matmul_ref(spikes, w)))
 
 
+def test_spike_matmul_skips_silent_blocks_exactly():
+    """Live presynaptic blocks after silent runs: the silent blocks'
+    index maps repeat the last live tile, and only live ones add."""
+    key = jax.random.PRNGKey(11)
+    spikes = jnp.zeros((128 * 6,), bool)
+    spikes = spikes.at[128 * 2 + 5].set(True).at[128 * 5:].set(True)
+    w = jax.random.randint(key, (128 * 6, 256), -3000, 3000, jnp.int16)
+    np.testing.assert_array_equal(
+        np.asarray(ops.spike_matmul(spikes, w)),
+        np.asarray(ref.spike_matmul_ref(spikes, w)))
+
+
 def test_spike_matmul_all_silent_is_zero():
     w = jnp.ones((256, 128), jnp.int16)
     out = ops.spike_matmul(jnp.zeros((256,), bool), w)
     assert int(jnp.abs(out).max()) == 0
+
+
+# ------------------------------------------------------ interpret choice
+@pytest.mark.parametrize("platform,want", [("cpu", True), ("tpu", False)])
+def test_interpret_follows_the_platform(monkeypatch, platform, want):
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert resolve_interpret() is want
+    assert resolve_interpret(not want) is (not want)
+
+
+def test_interpret_refuses_other_platforms(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        resolve_interpret()
 
 
 # ---------------------------------------------------------------- lif step

@@ -21,6 +21,7 @@ Pins the acceptance invariants:
 """
 import http.client
 import json
+import os
 import struct
 import subprocess
 import sys
@@ -706,6 +707,22 @@ def test_portal_respawns_killed_worker():
             assert s == 200 and b["digest"] == ref
         s, _, hz = http_req(portal.port, "GET", "/healthz")
         assert s == 200 and hz["status"] == "ok"
+
+
+def test_worker_process_imports_no_jax():
+    """Bridged front-end workers (`python -m repro.portal --worker`)
+    must never load jax: on a TPU host the dispatcher process holds the
+    chip, and a worker that touched jax would fail or hang."""
+    code = ("import sys; import repro.portal.__main__, "
+            "repro.portal.bridge, repro.portal.http, repro.portal.ws; "
+            "print(sorted(m for m in ('jax', 'numpy') "
+            "if m in sys.modules))")
+    out = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                         env={**os.environ,
+                              "PYTHONPATH": str(ROOT / "src")},
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "[]"
 
 
 # ------------------------------------- all four backends, forced devices
