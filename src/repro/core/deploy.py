@@ -35,12 +35,14 @@ shard.
 """
 from __future__ import annotations
 
+import time
 from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis.retrace import compile_counts
 from repro.analysis.validate import structural_error
 from repro.core import schedule as sched
 from repro.core.compile import CompiledNetwork
@@ -50,8 +52,15 @@ from repro.core.hiaer import HiAERNetwork
 from repro.core.mesh_runtime import MeshNetwork
 from repro.core.simulator import DenseSimulator
 from repro.core.spec import decode_pre
+from repro.obs.trace import Tracer
 
-__all__ = ["Deployment", "deploy"]
+__all__ = ["Deployment", "deploy", "LANE_STAGES"]
+
+# the children of a traced `run_lanes` span, in order: schedule encode,
+# pad and lane checks; the scratch lanes' key derivation; lane-state
+# gather and host -> device; the jitted lane step up to its first sync;
+# spikes, membranes and keys back to the host, with the lane write-back
+LANE_STAGES = ("encode", "keys", "upload", "step", "download")
 
 
 class MissingSynapseError(KeyError):
@@ -126,6 +135,9 @@ class Deployment:
         # int32 ceiling — no real lane id can collide with it
         self._scratch_root = jax.random.fold_in(self._lane_root,
                                                 2**31 - 1)
+        # where `run_lanes` records its spans; None or off records
+        # nothing (`SpikeServer.add_model` hands in the server's)
+        self.tracer: Optional[Tracer] = None
 
     # ------------------------------------------------------------ running
     @property
@@ -221,7 +233,9 @@ class Deployment:
         self._lane_V, self._lane_keys = V, new
 
     def run_lanes(self, lane_ids: Sequence[int], schedules,
-                  seeds: Optional[Sequence[int]] = None):
+                  seeds: Optional[Sequence[int]] = None, *,
+                  parent: Optional[dict] = None,
+                  spans: Optional[list] = None):
         """Stateful micro-batched run — the serving tier's dispatch
         primitive. Each entry b runs `schedules[b]` (all the same T) on
         lane `lane_ids[b]`: a real lane (>= 0) continues from its
@@ -232,10 +246,50 @@ class Deployment:
         a batch of ONE (the lane axis is elementwise on every backend),
         so micro-batching never leaks state — or noise — between
         clients. Returns (spikes (B, T, n) bool, membranes (B, n) int32
-        final per-lane potentials in global neuron order)."""
+        final per-lane potentials in global neuron order).
+
+        With `self.tracer` on, the call records a `run_lanes` span
+        (attributes: `bucket` rows, `T`, `live` resident lanes,
+        `bytes_up`, `bytes_down`, and `compiles`, the growth of the
+        backend's jit caches) with one child per `LANE_STAGES` entry,
+        `run_lanes.encode` to `run_lanes.download`, whose edges sit at
+        the syncs the call makes anyway. It also opens the profiler
+        annotation `run_lanes` with the span's monotonic start as its
+        `mono_ns` stat: on a device trace, the annotation's start minus
+        `mono_ns` maps every tracer span onto the trace's clock.
+        `parent` (a `Span.ctx()` dict) nests the span under a caller's;
+        `spans`, a list, takes the span records for the caller to commit
+        with its own, otherwise they are committed here."""
         if len(schedules) == 0:
             return (np.zeros((0, 0, self.compiled.n_neurons), bool),
                     np.zeros((0, self.compiled.n_neurons), np.int32))
+        tracer = self.tracer
+        if tracer is None or not tracer.on:
+            return self._run_lanes(lane_ids, schedules, seeds)
+        marks, attrs = [time.monotonic_ns()], {}
+        with jax.profiler.TraceAnnotation("run_lanes", mono_ns=marks[0]):
+            before = sum(compile_counts(self.impl).values())
+            out = self._run_lanes(lane_ids, schedules, seeds, marks, attrs)
+            attrs["compiles"] = sum(compile_counts(self.impl).values()) \
+                - before
+        root = tracer.span_record("run_lanes", ctx=parent, start=marks[0],
+                                  end=marks[-1], **attrs)
+        ctx = {"trace_id": root["trace_id"], "parent": root["span_id"]}
+        recs = [root] + [tracer.span_record(f"run_lanes.{name}", ctx=ctx,
+                                            start=a, end=b)
+                         for name, a, b in zip(LANE_STAGES, marks,
+                                               marks[1:])]
+        if spans is None:
+            tracer.record_batch(recs)
+        else:
+            spans.extend(recs)
+        return out
+
+    def _run_lanes(self, lane_ids, schedules, seeds, marks=None,
+                   attrs=None):
+        """`run_lanes`' work. `marks`, a list, gets the monotonic clock
+        at the end of each `LANE_STAGES` entry; `attrs`, a dict, the
+        dispatch's shape and bytes moved."""
         counts = self._pad(sched.encode_batch(schedules,
                                               self.compiled.n_axons))
         ids = np.asarray(list(lane_ids), np.int64)
@@ -254,20 +308,34 @@ class Deployment:
         if seeds is None:
             seeds = np.zeros((B,), np.int64)
         seeds = np.asarray(list(seeds), np.int64)
+        _mark(marks)
         keys = np.array(jax.vmap(
             lambda s: jax.random.fold_in(self._scratch_root, s))(
             jnp.asarray(seeds, jnp.int32)))
+        _mark(marks)
         V0 = self.impl.lane_state_zeros(B)
         if live.any():
             keys[live] = self._lane_keys[live_ids]
             V0[live] = self._lane_V[live_ids]
-        Vf, kf, spikes = self.impl.run_lanes(V0, jnp.asarray(keys),
-                                             counts)
+        args = (jnp.asarray(V0, jnp.int32), jnp.asarray(keys),
+                jnp.asarray(counts))
+        _mark(marks)
+        Vf, kf, spikes = self.impl.run_lanes(*args)
+        _mark(marks)
+        spikes = np.asarray(spikes, bool)
         Vf = np.asarray(Vf)
         if live.any():
             self._lane_V[live_ids] = Vf[live]
             self._lane_keys[live_ids] = np.asarray(kf)[live]
-        return spikes, self.impl.lanes_membrane(Vf)
+        membranes = self.impl.lanes_membrane(Vf)
+        _mark(marks)
+        if attrs is not None:
+            attrs.update(bucket=B, T=counts.shape[1],
+                         live=int(live.sum()),
+                         bytes_up=V0.nbytes + keys.nbytes + counts.nbytes,
+                         bytes_down=spikes.nbytes + Vf.nbytes
+                         + (keys.nbytes if live.any() else 0))
+        return spikes, membranes
 
     def lane_membrane(self, lane: int) -> np.ndarray:
         """Current (n,) membrane potentials of one allocated lane, in
@@ -444,6 +512,12 @@ class Deployment:
 
     def write_synapse(self, pre: int, post: int, weight: int) -> None:
         self.write_synapses([pre], [post], [int(weight)])
+
+
+def _mark(marks: Optional[list]) -> None:
+    """Append the monotonic clock (ns) to `marks`, if a list."""
+    if marks is not None:
+        marks.append(time.monotonic_ns())
 
 
 def deploy(compiled: CompiledNetwork, *, seed: int = 0,

@@ -451,17 +451,17 @@ class MeshNetwork:
     def run_lanes(self, V0, keys, counts):
         """Stateful batched run for the serving tier. V0: (B, C, n_max)
         int32 per-core membranes, keys: (B,) PRNG keys, counts:
-        (B, T, A) int32. All B lanes share one collective per hierarchy
-        level per timestep (the lane axis rides inside shard_map).
-        Returns (V_final, keys_final, spikes (B, T, n) bool); the
-        engine's own sequential state is untouched."""
+        (B, T, A) int32, all on the device. All B lanes share one
+        collective per hierarchy level per timestep (the lane axis rides
+        inside shard_map). Returns (V_final, keys_final, spikes (B, T, n)
+        bool) on the device, once the step's tallies (its first sync)
+        are in; the engine's own sequential state is untouched."""
         B, T = counts.shape[0], counts.shape[1]
         self.counter.timesteps += B * T
         Vc, keys, spikes, prs, rrs, trs = self._jit_run_lanes(
-            jnp.asarray(V0, jnp.int32), keys, jnp.asarray(counts),
-            self._tables)
+            V0, keys, counts, self._tables)
         self.counter.tally(prs, rrs, trs)
-        return Vc, keys, np.asarray(spikes, bool)
+        return Vc, keys, spikes
 
     def lanes_membrane(self, V_lanes) -> np.ndarray:
         """Per-lane (C, n_max) state -> (B, n) membranes in global

@@ -10,8 +10,7 @@ thread through).
 from .logs import JsonLogger, request_record
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       log_buckets, merge_snapshots, parse_prometheus,
-                      render_snapshot, snapshot_by_worker,
-                      snapshot_with_label)
+                      render_snapshot, snapshot_by_worker)
 from .telemetry import Telemetry
 from .trace import (Span, Tracer, chrome_trace, new_trace_id,
                     validate_chrome_trace)
@@ -21,7 +20,7 @@ __all__ = [
     "validate_chrome_trace",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "log_buckets",
     "merge_snapshots", "render_snapshot", "snapshot_by_worker",
-    "snapshot_with_label", "parse_prometheus",
+    "parse_prometheus",
     "JsonLogger", "request_record",
     "Telemetry",
 ]

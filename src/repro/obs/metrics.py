@@ -39,8 +39,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "log_buckets", "render_snapshot", "merge_snapshots",
-           "snapshot_by_worker", "snapshot_with_label",
-           "parse_prometheus"]
+           "snapshot_by_worker", "parse_prometheus"]
 
 
 def log_buckets(lo: float = 0.25, hi: float = 8000.0,
@@ -437,24 +436,6 @@ def snapshot_by_worker(snapshot: dict, worker) -> dict:
             "samples": [[new + sname[len(name):],
                          list(key) + [str(worker)], v,
                          [list(p) for p in extra]]
-                        for sname, key, v, extra in fam["samples"]],
-        }
-    return out
-
-
-def snapshot_with_label(snapshot: dict, label: str,
-                        value: str) -> dict:
-    """Append `label=value` to every sample of a snapshot — the
-    per-worker breakdown (`worker="<pid>"`) kept alongside the
-    aggregated series."""
-    out = {}
-    for name, fam in snapshot.items():
-        out[name] = {
-            "kind": fam["kind"], "help": fam["help"],
-            "labelnames": list(fam["labelnames"]),
-            "samples": [[sname, key, v,
-                         [list(p) for p in extra]
-                         + [[label, str(value)]]]
                         for sname, key, v, extra in fam["samples"]],
         }
     return out

@@ -48,12 +48,6 @@ class Telemetry:
         self.tracer.on = value
         self.metrics.on = value
 
-    @classmethod
-    def disabled(cls) -> "Telemetry":
-        """A telemetry bundle with tracing + metrics off and no log
-        sink — the cheapest configuration, for overhead baselines."""
-        return cls(on=False)
-
     def stats(self) -> dict:
         return {"on": self.on, "tracer": self.tracer.stats(),
                 "log_written": self.log.written}

@@ -147,6 +147,11 @@ class SpikeServer:
         self._stats_lock = threading.Lock()
         self.latencies_ms: List[float] = []
         self.batch_sizes: List[int] = []
+        # {span name: [dispatches, total ms]} of the dispatcher's own
+        # spans (`SERVE_STAGES`, `run_lanes` and its stages), summed as
+        # they are committed: stats() reports them whatever the ring
+        # bound has dropped since
+        self.stage_ms: Dict[str, List[float]] = {}
         # telemetry is always AVAILABLE (a default bundle is built when
         # none is passed); only its cost profile changes with tel.on
         self.tel = telemetry if telemetry is not None else Telemetry()
@@ -292,6 +297,7 @@ class SpikeServer:
                                 "deployment=")
             deployment = deploy(compiled, seed=seed, **deploy_kw)
         deployment.alloc_lanes(n_sessions)
+        deployment.tracer = self.tel.tracer
         m = ResidentModel(name=name, dep=deployment, window=int(window),
                           sessions=SessionStore(n_sessions))
         self.models[name] = m
@@ -506,8 +512,10 @@ class SpikeServer:
     def _dispatch_loop(self) -> None:
         while True:
             self._last_tick = time.monotonic()
+            t_take = time.monotonic_ns()
             items = self._buf.take(self.max_batch, self.max_wait_s,
                                    coalesce=self._coalesce)
+            t_taken = time.monotonic_ns()
             if not items:
                 if self._stop.is_set():
                     break
@@ -536,7 +544,7 @@ class SpikeServer:
                         if isinstance(items[0], Reconfigure):
                             self._apply_reconfigure(items[0])
                         else:
-                            self._run_batch(items)
+                            self._run_batch(items, t_take, t_taken)
                     finally:
                         if self._wd.stop()["straggler"]:
                             self._straggler_until = time.monotonic() \
@@ -662,11 +670,21 @@ class SpikeServer:
         m.reconfig_applied += 1
         _resolve(rc.future, m.dep.weight_uploads)
 
-    def _run_batch(self, reqs: List[Request]) -> None:
+    def _run_batch(self, reqs: List[Request], t_take: int,
+                   t_taken: int) -> None:
         """ONE `run_lanes` dispatch for the whole micro-batch: stack
         the (window, A) counts, bucket B up to a power of two with
         scratch rows (lane -1, zero events), execute, slice each
-        client's own lane back out."""
+        client's own lane back out. `t_take` and `t_taken` (monotonic
+        ns) bracket the `take` that collected the batch.
+
+        With the tracer on, the dispatch records `serve.batch`, from the
+        take to the end of this call, over `serve.collect` (the take:
+        the wait for requests and `max_wait`), `serve.assemble`
+        (expiry, stack, lane lookup, undo snapshot), the `run_lanes`
+        tree of `Deployment.run_lanes` and `serve.resolve` (results,
+        span and metric commits, futures): one `record_batch` with the
+        per-request spans."""
         # injection sites: slow_batch sleeps (hang/watchdog paths),
         # batch_exception raises (the batch-poison recovery path);
         # both fire BEFORE any lane state is read or advanced, so a
@@ -695,7 +713,23 @@ class SpikeServer:
             self._undo = (m, [ln for ln, _ in live], snapV, snapK,
                           [f for _, f in live])
         t_dispatch = time.monotonic_ns()
-        spikes, membranes = m.dep.run_lanes(lanes, counts, seeds=seeds)
+        tracer = self.tel.tracer
+        on = tracer.on                  # one reading for the whole batch
+        span_out, resolved, ctx = [], [], None
+        if on:
+            batch = tracer.span_record("serve.batch", start=t_take,
+                                       end=None, model=m.name, B=B,
+                                       bucket=Bp)
+            ctx = {"trace_id": batch["trace_id"],
+                   "parent": batch["span_id"]}
+            span_out += [batch,
+                         tracer.span_record("serve.collect", ctx=ctx,
+                                            start=t_take, end=t_taken),
+                         tracer.span_record("serve.assemble", ctx=ctx,
+                                            start=t_taken,
+                                            end=t_dispatch)]
+        spikes, membranes = m.dep.run_lanes(lanes, counts, seeds=seeds,
+                                            parent=ctx, spans=span_out)
         t_done = time.monotonic_ns()
         dispatch_ms = (t_done - t_dispatch) / 1e6
         m.trace_shapes.add((Bp, m.window))
@@ -703,9 +737,7 @@ class SpikeServer:
         m.requests += B
         m.batches += 1
         m.lane_steps += B * m.window
-        tracer = self.tel.tracer
         lats, qwaits = [], []
-        span_out, resolved = [], []
         for i, r in enumerate(reqs):
             lat = (done - r.t_submit) * 1e3
             lats.append(lat)
@@ -723,7 +755,7 @@ class SpikeServer:
             # per request would dominate telemetry's 5% overhead
             # envelope at high request rates
             tid = (r.trace or {}).get("trace_id", "")
-            if tracer.on:
+            if on:
                 qd = tracer.span_record("queue_wait", ctx=r.trace,
                                         start=r.t_submit_ns,
                                         end=t_assembled, model=r.model)
@@ -738,13 +770,16 @@ class SpikeServer:
                 latency_ms=lat, batch_size=B, model=r.model,
                 session=r.session, queue_wait_ms=qwaits[-1],
                 dispatch_ms=dispatch_ms, bucket=Bp, trace_id=tid)))
-        if tracer.on:
-            # commit spans BEFORE resolving futures: a client that has
-            # its response can immediately fetch the full trace
+        if on:
+            # commit spans and metrics BEFORE resolving futures: a
+            # client that has its response can immediately fetch the
+            # full trace and finds itself counted. The dispatch's own
+            # serve.batch and serve.resolve go in open and are closed
+            # below, after the work they cover
+            resolve = tracer.span_record("serve.resolve", ctx=ctx,
+                                         start=t_done, end=None)
+            span_out.append(resolve)
             tracer.record_batch(span_out)
-        for fut, res in resolved:
-            _resolve(fut, res)
-        if tracer.on:
             # metric updates are per BATCH, not per request: one key
             # build + lock acquire each, so obs-on stays within the
             # bench's 5% overhead envelope at high request rates
@@ -759,6 +794,17 @@ class SpikeServer:
         with self._stats_lock:
             self.latencies_ms.extend(lats)
             self.batch_sizes.append(B)
+        for fut, res in resolved:
+            _resolve(fut, res)
+        if on:
+            resolve["end"] = batch["end"] = time.monotonic_ns()
+            with self._stats_lock:
+                for rec in span_out:
+                    if rec["name"].startswith(("serve.", "run_lanes")):
+                        tot = self.stage_ms.setdefault(rec["name"],
+                                                       [0, 0.0])
+                        tot[0] += 1
+                        tot[1] += (rec["end"] - rec["start"]) / 1e6
 
     # ------------------------------------------------------------ health
     def health(self) -> dict:
@@ -903,18 +949,23 @@ class SpikeServer:
 
     # ------------------------------------------------------------- stats
     def reset_stats(self) -> None:
-        """Drop accumulated latency/batch samples (e.g. after warmup,
-        so percentiles reflect serving, not tracing)."""
+        """Drop accumulated latency/batch samples and stage totals (e.g.
+        after warmup, so percentiles reflect serving, not tracing)."""
         with self._stats_lock:
             self.latencies_ms.clear()
             self.batch_sizes.clear()
+            self.stage_ms.clear()
 
     def stats(self) -> dict:
-        """Serving statistics: latency percentiles, occupancy, and the
-        ingestion buffer's swap accounting."""
+        """Serving statistics: latency percentiles, occupancy, the
+        ingestion buffer's swap accounting, and under `stages` each
+        dispatcher span's count and total ms (`serve.*`, `run_lanes`,
+        `run_lanes.*`; empty while the tracer is off)."""
         with self._stats_lock:
             lats = np.asarray(self.latencies_ms, float)
             sizes = list(self.batch_sizes)
+            stages = {name: {"dispatches": n, "total_ms": ms}
+                      for name, (n, ms) in self.stage_ms.items()}
         out = {
             "requests": int(lats.size),
             "batches": len(sizes),
@@ -924,6 +975,7 @@ class SpikeServer:
             "p99_ms": float(np.percentile(lats, 99)) if lats.size
             else 0.0,
             "buffer": self._buf.stats(),
+            "stages": stages,
             "models": {name: {"requests": mm.requests,
                               "batches": mm.batches,
                               "lane_steps": mm.lane_steps,
