@@ -78,7 +78,7 @@ def _bench_routing(quiet=False, smoke=False):
     spk = (V + xi) > theta
     V_mid = jnp.where(spk, 0, V + xi)
     V_mid = jnp.where(is_lif, nrn.leak(V_mid, lam), 0)
-    syn, _, _ = route_k.route(tables, counts, spk, n)
+    syn = route_k.route(tables, counts, spk, n)[0]
     V_o = nrn.integrate_phase(V_mid, syn)
     assert np.array_equal(np.asarray(V_f), np.asarray(V_o))
     assert np.array_equal(np.asarray(spk_f), np.asarray(spk))

@@ -250,8 +250,9 @@ class Deployment:
 
         With `self.tracer` on, the call records a `run_lanes` span
         (attributes: `bucket` rows, `T`, `live` resident lanes,
-        `bytes_up`, `bytes_down`, and `compiles`, the growth of the
-        backend's jit caches) with one child per `LANE_STAGES` entry,
+        `bytes_up`, `bytes_down`, `compiles`, the growth of the
+        backend's jit caches, and what the backend's `lane_span_attrs`
+        reports, if it has one) with one child per `LANE_STAGES` entry,
         `run_lanes.encode` to `run_lanes.download`, whose edges sit at
         the syncs the call makes anyway. It also opens the profiler
         annotation `run_lanes` with the span's monotonic start as its
@@ -272,6 +273,7 @@ class Deployment:
             out = self._run_lanes(lane_ids, schedules, seeds, marks, attrs)
             attrs["compiles"] = sum(compile_counts(self.impl).values()) \
                 - before
+            attrs.update(getattr(self.impl, "lane_span_attrs", dict)())
         root = tracer.span_record("run_lanes", ctx=parent, start=marks[0],
                                   end=marks[-1], **attrs)
         ctx = {"trace_id": root["trace_id"], "parent": root["span_id"]}
