@@ -41,6 +41,7 @@ class Run:
     plan: Plan
     requests: List[Request]
     setup_s: float
+    chips: int                    # the cell's, which the program spans
     t0: float = 0.0               # first window due
     t_end: float = 0.0            # last answer
     server_stats: dict = field(default_factory=dict)
@@ -74,13 +75,13 @@ def device_info(chips: int) -> dict:
             "count": len(devs)}
 
 
-def peak_bytes(chips: int) -> Optional[int]:
+def peak_bytes(chips: int) -> List[int]:
+    """Each chip's peak bytes in use, where the backend reports it."""
     import jax
 
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
              for d in jax.devices()[:chips]]
-    peaks = [p for p in peaks if p is not None]
-    return max(peaks) if peaks else None
+    return [p for p in peaks if p is not None]
 
 
 def lookup_peaks(layout: Layout, kind: str) -> dict:
@@ -186,14 +187,16 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     layout = Layout(root)
     cell = layout.cell(workload)
     chips = int(cell["chips"])
+    cfg = layout.config(cell["config"])
+    from benchkit import program
+    target = program.deploy_target(cfg, chips)
     t0 = time.perf_counter()
-    device = device_check(chips) if require_chip else device_info(1)
+    device = device_check(chips) if require_chip else device_info(chips)
     log(f"backend start: {time.perf_counter() - t0:.2f} s")
     peaks = lookup_peaks(layout, device["kind"]) if require_chip else None
-    cfg = layout.config(cell["config"])
     tparams = layout.traffic(cell["traffic"])
     net_seed, dep_seed, traffic_seed = derive_seeds(seed)
-    log(f"device {device}; cell {workload}; seed {seed}")
+    log(f"device {device}; cell {workload} ({target}); seed {seed}")
 
     t0 = time.perf_counter()
     net = layout.network_builder(cfg["kind"]).build(cfg, net_seed)
@@ -207,11 +210,11 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     log(f"traffic plan: {len(plan.requests)} windows, "
         f"{time.perf_counter() - t0:.2f} s")
 
-    from benchkit import program
-    compiled = program.compile_network(net, log)
+    compiled = program.compile_network(net, cfg, log)
     lanes = plan.sessions if plan.loop == "closed" else 1
-    prog = program.Served(compiled, seed=dep_seed, window=plan.window,
-                          lanes=lanes, max_batch=plan.max_batch,
+    prog = program.Served(compiled, chips=chips, seed=dep_seed,
+                          window=plan.window, lanes=lanes,
+                          max_batch=plan.max_batch,
                           max_wait_ms=plan.max_wait_ms,
                           buckets=plan.buckets, log=log)
     del compiled
@@ -237,10 +240,11 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
         stall_file.seek(0)
         stalls = stall_file.read()
     run = Run(net=net, plan=plan, requests=requests, setup_s=setup_s,
-              peaks=peaks, server_stats=prog.server.stats())
+              chips=chips, peaks=peaks, server_stats=prog.server.stats())
     if trace:
         tr.stop()
-    mem = peak_bytes(chips) if require_chip else None
+    chip_mem = peak_bytes(chips) if require_chip else []
+    mem = max(chip_mem, default=None)
     prog.close()
     del prog
     gc.collect()
@@ -256,7 +260,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
         f"{1e3 * float(np.median(late)) if late else 0.0:.3f} ms median; "
         f"compiles inside the window: {compiles.count}; "
         f"gc pauses: {pauses}; "
-        f"peak_bytes_in_use: {mem}; "
+        f"peak_bytes_in_use: {mem} (by chip {chip_mem}); "
         f"stack dumps of late windows: {stalls.count('Timeout (')}")
     very_late = [r for r in requests if r.submit - r.due >= 0.1]
     if very_late:
@@ -270,6 +274,15 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
         lat = stats.latencies_ms(requests)
         log("latency over all windows, ms: " + ", ".join(
             f"p{q} {np.percentile(lat, q):.1f}" for q in (50, 95, 99)))
+    elif run.answered:
+        # a round's last answer to the next's: one long round is a stall,
+        # every round longer a slower step
+        ks = sorted({r.k for r in run.answered})
+        ends = [max(r.done for r in run.answered if r.k == k) for k in ks]
+        rounds = np.diff([run.t0] + ends) * 1e3
+        log(f"closed-loop rounds, ms: fastest {rounds.min():.1f}, median "
+            f"{np.median(rounds):.1f}, slowest {rounds.max():.1f} "
+            f"(round {int(np.argmax(rounds))} of {len(rounds)})")
     log(f"host peak RSS: "
         f"{resource.getrusage(resource.RUSAGE_SELF).ru_maxrss} KiB")
     log(f"firing rates: {firing_rates(net, requests)}")

@@ -32,15 +32,63 @@ def to_spec(net: Network):
     return spec
 
 
-def compile_network(net: Network, log):
-    """`compile_spec(target="engine")` of the network, as a user
-    compiles it."""
-    from repro.core.compile import compile_spec
+TARGETS = ("engine", "hiaer", "mesh")
+# the Hierarchy a hiaer or mesh configuration states, in its own keys
+HIERARCHY_KEYS = ("servers", "fpgas_per_server", "cores_per_fpga",
+                  "neurons_per_core")
 
+
+class Refused(ValueError):
+    """A cell whose configuration cannot be deployed over its chips."""
+
+
+def deploy_target(cfg: dict, chips: int) -> str:
+    """The target `cfg` names, refused where the cell's `chips` cannot
+    hold it: a mesh spreads whole cores evenly over the chips; the
+    engine and hiaer run on one."""
+    target = cfg.get("target")
+    if target not in TARGETS:
+        raise Refused(f"configuration {cfg.get('name')!r} names target "
+                      f"{target!r}; the benchmark deploys one of "
+                      f"{TARGETS}")
+    missing = [k for k in HIERARCHY_KEYS if k not in cfg]
+    if target != "engine" and missing:
+        raise Refused(f"a {target} configuration states its hierarchy; "
+                      f"{cfg.get('name')!r} lacks {missing}")
+    if target != "mesh":
+        if chips != 1:
+            raise Refused(f"a {target} configuration runs on one chip; "
+                          f"the cell asks for {chips}")
+        return target
+    cores = int(cfg["servers"]) * int(cfg["fpgas_per_server"]) \
+        * int(cfg["cores_per_fpga"])
+    if chips < 1 or cores % chips:
+        raise Refused(f"a mesh of {cores} cores cannot be spread evenly "
+                      f"over {chips} chips")
+    return target
+
+
+def compile_network(net: Network, cfg: dict, log):
+    """`compile_spec` of the network for the target `cfg` names, as a
+    user compiles it. hiaer and mesh get the configuration's hierarchy
+    and contiguous blocks of `neurons_per_core` as the placement: a
+    random network has no locality for the partitioner to find, and at
+    full size the partitioner is most of the host build."""
+    from repro.core.compile import compile_spec
+    from repro.core.partition import Hierarchy
+
+    target = cfg["target"]
     t0 = time.perf_counter()
-    compiled = compile_spec(to_spec(net), target="engine")
-    log(f"compile_spec: {net.n_neurons} neurons, {net.n_synapses} "
-        f"synapses, {time.perf_counter() - t0:.2f} s")
+    spec = to_spec(net)
+    kw = {}
+    if target != "engine":
+        per_core = int(cfg["neurons_per_core"])
+        kw["hierarchy"] = Hierarchy(*(int(cfg[k]) for k in HIERARCHY_KEYS))
+        kw["placement"] = dict(enumerate(
+            (np.arange(net.n_neurons) // per_core).tolist()))
+    compiled = compile_spec(spec, target=target, **kw)
+    log(f"compile_spec(target={target!r}): {net.n_neurons} neurons, "
+        f"{net.n_synapses} synapses, {time.perf_counter() - t0:.2f} s")
     return compiled
 
 
@@ -60,19 +108,25 @@ class Served:
     """One network resident in a `SpikeServer`, warmed for the batch
     shapes its traffic dispatches."""
 
-    def __init__(self, compiled, *, seed: int, window: int, lanes: int,
-                 max_batch: int, max_wait_ms: float, buckets, log):
+    def __init__(self, compiled, *, chips: int, seed: int, window: int,
+                 lanes: int, max_batch: int, max_wait_ms: float, buckets,
+                 log):
         from repro.serve import SpikeServer
 
         self.server = SpikeServer(max_batch=max_batch,
                                   max_wait_ms=max_wait_ms)
+        target = compiled.target
+        kw = {"n_devices": chips} if target == "mesh" else {}
         t0 = time.perf_counter()
         self.model = self.server.add_model(MODEL, compiled, window=window,
-                                           n_sessions=lanes, seed=seed)
-        log(f"deploy: {time.perf_counter() - t0:.2f} s")
+                                           n_sessions=lanes, seed=seed,
+                                           **kw)
         dep = self.model.dep
+        log(f"deploy ({target}, n_devices "
+            f"{getattr(dep.impl, 'n_devices', 1)}): "
+            f"{time.perf_counter() - t0:.2f} s")
         _annotate(dep, "bench.run_lanes")
-        _annotate(dep.impl, "bench.engine.run_lanes")
+        _annotate(dep.impl, f"bench.{target}.run_lanes")
         t0 = time.perf_counter()
         for b in buckets:
             dep.run_lanes([-1] * b, np.zeros((b, window, compiled.n_axons),

@@ -31,9 +31,11 @@ def necessary(n_neurons: int, lane_steps: int, events: int
             + float(events) * EVENT_OPS)
 
 
-def least_time(n_bytes: float, n_ops: float, peaks: dict
+def least_time(n_bytes: float, n_ops: float, peaks: dict, chips: int
                ) -> Tuple[float, str]:
-    """Seconds the chip needs at least, and which bound sets it."""
-    t_mem = n_bytes / float(peaks["hbm_bytes_per_s"])
-    t_ops = n_ops / float(peaks["int8_ops_per_s"])
+    """Seconds `chips` chips of one chip's `peaks` need at least, and
+    which bound sets it: the work spread evenly over them, so against
+    a device time averaged over the chips (`Trace.busy_s`)."""
+    t_mem = n_bytes / (chips * float(peaks["hbm_bytes_per_s"]))
+    t_ops = n_ops / (chips * float(peaks["int8_ops_per_s"]))
     return (t_mem, "bandwidth") if t_mem >= t_ops else (t_ops, "compute")

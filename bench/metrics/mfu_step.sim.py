@@ -1,6 +1,7 @@
 """The whole lane step's share of its roofline, in %: the least time the
-chip needs for the window's necessary bytes and ops (`benchkit.work`)
-over the device time of the lane-step program in the traced window."""
+cell's chips need for the window's necessary bytes and ops
+(`benchkit.work`) over the device time of the lane-step program in the
+traced window, averaged over the chips."""
 import sys
 
 from benchkit import work
@@ -17,6 +18,6 @@ def read(run):
         return None
     n_bytes, n_ops = work.necessary(run.net.n_neurons, steps,
                                     sum(run.events.values()))
-    t, bound = work.least_time(n_bytes, n_ops, run.peaks)
+    t, bound = work.least_time(n_bytes, n_ops, run.peaks, run.chips)
     print(f"mfu_step.sim: bound by {bound}", file=sys.stderr)
     return 100.0 * t / busy

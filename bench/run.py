@@ -3,13 +3,16 @@
     python3 bench/run.py --workload core125k.sim --seed 7 --seconds 30 \
         --trace 0
 
-Builds the cell's network from the seed, deploys it in a `SpikeServer`,
-warms up the batch shapes its traffic dispatches, offers that traffic
-for `--seconds`, checks every answered window against the plain
-reference, and prints one JSON line on standard output (diagnostics and
-the compared numbers go to standard error). `--trace 1` reports the
-per-layer metrics from a profiler trace of the window in place of the
-end-to-end ones. Exits non-zero, printing no result, without a TPU.
+Builds the cell's network from the seed, compiles it for the target its
+configuration names (`engine`, `hiaer` or `mesh`), deploys it in a
+`SpikeServer` over the cell's chips, warms up the batch shapes its
+traffic dispatches, offers that traffic for `--seconds`, checks every
+answered window against the plain reference, and prints one JSON line
+on standard output (diagnostics and the compared numbers go to standard
+error). `--trace 1` reports the per-layer metrics from a profiler trace
+of the window in place of the end-to-end ones. Exits non-zero, printing
+no result, without a TPU (3) or for a cell whose chips cannot hold its
+configuration's target (2).
 
 The persistent compilation cache lives in `bench/.cache/jax` unless
 `JAX_COMPILATION_CACHE_DIR` names another directory.
@@ -41,6 +44,7 @@ def main(argv=None) -> int:
     import jax
 
     from benchkit.harness import CACHE, NoChip, run_cell
+    from benchkit.program import Refused
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir",
@@ -52,6 +56,9 @@ def main(argv=None) -> int:
     except NoChip as e:
         print(f"bench: {e}", file=sys.stderr)
         return 3
+    except Refused as e:
+        print(f"bench: cell {args.workload} refused: {e}", file=sys.stderr)
+        return 2
     print(json.dumps(result), flush=True)
     return 0
 

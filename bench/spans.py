@@ -65,8 +65,10 @@ def main(argv=None) -> int:
     log = lambda m: print(m, file=sys.stderr, flush=True)  # noqa: E731
     layout = Layout(ROOT)
     cell = layout.cell(args.workload)
-    device_check(int(cell["chips"]))
+    chips = int(cell["chips"])
     cfg = layout.config(cell["config"])
+    program.deploy_target(cfg, chips)
+    device_check(chips)
     params = layout.traffic(cell["traffic"])
     net_seed, dep_seed, traffic_seed = derive_seeds(args.seed)
     net = layout.network_builder(cfg["kind"]).build(cfg, net_seed)
@@ -76,8 +78,8 @@ def main(argv=None) -> int:
     # a ring that holds every span of a window, for the trace arm
     server_mod.Telemetry = functools.partial(
         Telemetry, trace_capacity=TRACE_CAPACITY)
-    prog = program.Served(program.compile_network(net, log),
-                          seed=dep_seed, window=plan.window,
+    prog = program.Served(program.compile_network(net, cfg, log),
+                          chips=chips, seed=dep_seed, window=plan.window,
                           lanes=plan.sessions if plan.loop == "closed"
                           else 1, max_batch=plan.max_batch,
                           max_wait_ms=plan.max_wait_ms,

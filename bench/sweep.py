@@ -36,8 +36,10 @@ def main(argv=None) -> int:
 
     layout = Layout(ROOT)
     cell = layout.cell(args.workload)
-    device_check(int(cell["chips"]))
+    chips = int(cell["chips"])
     cfg = layout.config(cell["config"])
+    program.deploy_target(cfg, chips)
+    device_check(chips)
     params = layout.traffic(cell["traffic"])
     if params["loop"] != "open":
         raise SystemExit("the sweep is for open-loop traffic")
@@ -45,10 +47,11 @@ def main(argv=None) -> int:
     net = layout.network_builder(cfg["kind"]).build(cfg, net_seed)
     make_input = layout.input_maker(params["input"]["kind"]).make
     log = lambda m: print(m, file=sys.stderr, flush=True)  # noqa: E731
-    compiled = program.compile_network(net, log)
+    compiled = program.compile_network(net, cfg, log)
     plan = make_plan(params, make_input, net.n_axons, traffic_seed, 1.0)
-    prog = program.Served(compiled, seed=dep_seed, window=plan.window,
-                          lanes=1, max_batch=plan.max_batch,
+    prog = program.Served(compiled, chips=chips, seed=dep_seed,
+                          window=plan.window, lanes=1,
+                          max_batch=plan.max_batch,
                           max_wait_ms=plan.max_wait_ms,
                           buckets=plan.buckets, log=log)
     for rate in args.rates:

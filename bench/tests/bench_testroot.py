@@ -1,7 +1,7 @@
 """A checkout-shaped directory for the benchmark's tests: a copy of
-`bench/` and `BENCHMARK.json`, plus two tiny configurations, two tiny
-traffic mixes and two cells that use them, all added as new files and
-new entries only."""
+`bench/` and `BENCHMARK.json`, plus tiny configurations (one for each
+deploy target), two tiny traffic mixes and cells that use them, all
+added as new files and new entries only."""
 from __future__ import annotations
 
 import json
@@ -15,15 +15,28 @@ for p in (str(REPO / "src"), str(BENCH)):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+TINY_LIF = {"kind": "random_lif", "axons": 32, "synapses_per_source": 40,
+            "max_in_degree": 70,
+            "neuron": {"model": "LIF", "threshold": 60000, "nu": 0,
+                       "lam": 4}}
+# four cores of 150 neurons, two FPGAs of two cores: a mesh over four
+# devices exchanges spikes at both levels
+TINY_HIERARCHY = {"cores": 4, "neurons_per_core": 150, "servers": 1,
+                  "fpgas_per_server": 2, "cores_per_fpga": 2}
+
 TINY_FILES = {
     "configs/tiny-core.json": {
-        "name": "tiny-core", "kind": "random_lif", "cores": 1,
-        "neurons_per_core": 600, "axons": 32, "synapses_per_source": 40,
-        "max_in_degree": 70,
-        "neuron": {"model": "LIF", "threshold": 60000, "nu": 0,
-                   "lam": 4}},
+        "name": "tiny-core", "target": "engine", "cores": 1,
+        "neurons_per_core": 600, **TINY_LIF},
+    "configs/tiny-hiaer.json": {
+        "name": "tiny-hiaer", "target": "hiaer", **TINY_HIERARCHY,
+        **TINY_LIF},
+    "configs/tiny-mesh.json": {
+        "name": "tiny-mesh", "target": "mesh", **TINY_HIERARCHY,
+        **TINY_LIF},
     "configs/tiny-mlp.json": {
-        "name": "tiny-mlp", "kind": "layered", "inputs": 784,
+        "name": "tiny-mlp", "kind": "layered", "target": "engine",
+        "inputs": 784,
         "layers": [40, 20, 10],
         "hidden_neuron": {"model": "ANN", "threshold": 0, "nu": -32},
         "output_neuron": {"model": "ANN", "threshold": 2 ** 30,
@@ -36,29 +49,43 @@ TINY_FILES = {
         "window": 8, "max_batch": 4, "max_wait_ms": 2.0,
         "input": {"kind": "digits", "on_rate": 0.5, "flip": 0.03}},
 }
-# new cell -> (config, traffic, the benchmark's cell whose metrics it takes)
+# new one-chip cell -> (config, traffic, the benchmark's cell whose
+# metrics it takes)
 TINY_CELLS = {"tiny.sim": ("tiny-core", "tiny_sessions", "core125k.sim"),
-              "tiny.serve": ("tiny-mlp", "tiny_poisson", "mlp.serve")}
+              "tiny.serve": ("tiny-mlp", "tiny_poisson", "mlp.serve"),
+              "tiny.hiaer": ("tiny-hiaer", "tiny_sessions", "core125k.sim"),
+              "tiny.mesh": ("tiny-mesh", "tiny_sessions", "core125k.sim")}
+# the same for a cell on four chips, which runs in a process that has
+# four devices
+TINY_CELLS_4 = {"tiny.mesh4": ("tiny-mesh", "tiny_sessions",
+                               "core125k.sim")}
+
+
+def target_of(cell: str) -> str:
+    """The deploy target a tiny cell's configuration names."""
+    config = {**TINY_CELLS, **TINY_CELLS_4}[cell][0]
+    return TINY_FILES[f"configs/{config}.json"]["target"]
 
 
 def make_root(tmp: Path) -> Path:
     root = Path(tmp) / "checkout"
     shutil.copytree(BENCH, root / "bench", ignore=shutil.ignore_patterns(
         ".cache", "__pycache__"))
+    spec = json.loads((REPO / "BENCHMARK.json").read_text())
     for rel, body in TINY_FILES.items():
         path = root / "bench" / rel
         if path.exists():
             raise FileExistsError(path)
         path.write_text(json.dumps(body))
-    spec = json.loads((REPO / "BENCHMARK.json").read_text())
-    for name in ("tiny-core", "tiny-mlp"):
-        spec["configs"].append({
-            "name": name, "source": "tiny test network",
-            "file": f"bench/configs/{name}.json", "reduced": [],
-            "why": "tiny"})
-    for cell, (config, traffic, like) in TINY_CELLS.items():
+        if rel.startswith("configs/"):
+            spec["configs"].append({
+                "name": body["name"], "source": "tiny test network",
+                "file": f"bench/{rel}", "reduced": [], "why": "tiny"})
+    cells = [(cell, parts, 1) for cell, parts in TINY_CELLS.items()] \
+        + [(cell, parts, 4) for cell, parts in TINY_CELLS_4.items()]
+    for cell, (config, traffic, like), chips in cells:
         spec["workloads"].append({"name": cell, "config": config,
-                                  "traffic": traffic, "chips": 1,
+                                  "traffic": traffic, "chips": chips,
                                   "why": "tiny"})
         for group in ("end_to_end", "per_layer"):
             for m in spec[group]:

@@ -126,7 +126,7 @@ def test_op_and_module_names():
 # -------------------------------------------------------------- metrics
 def _run(requests, **kw):
     base = dict(requests=requests, setup_s=1.0, trace=None, peaks=None,
-                server_stats={}, events={})
+                server_stats={}, events={}, chips=1)
     base.update(kw)
     run = SimpleNamespace(**base)
     run.answered = [r for r in requests if r.answered]
@@ -171,15 +171,17 @@ def test_queue_and_batch_readers():
     assert reader("batch_fill.serve")(run) == pytest.approx(25.0)
 
 
-def test_mfu_is_necessary_time_over_step_time():
+@pytest.mark.parametrize("chips", [1, 4])
+def test_mfu_is_necessary_time_over_step_time(chips):
     peaks = {"hbm_bytes_per_s": 1e9, "int8_ops_per_s": 1e12}
     reqs = [_req(0, 0.0, 1.0, steps=10)]
+    # busy time is averaged over the chips, the work spread over them
     trace = SimpleNamespace(busy_s=lambda module=None: 2.0)
     run = _run(reqs, trace=trace, peaks=peaks, events={0: 1000},
-               net=SimpleNamespace(n_neurons=100))
+               net=SimpleNamespace(n_neurons=100), chips=chips)
     # 10 lane-steps x 100 neurons x 8 B + 1000 events x 6 B = 14,000 B
     assert reader("mfu_step.sim")(run) == pytest.approx(
-        100 * 14_000 / 1e9 / 2.0)
+        100 * 14_000 / 1e9 / 2.0 / chips)
     assert reader("mfu_step.sim")(_run(reqs)) is None
 
 
