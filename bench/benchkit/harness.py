@@ -297,7 +297,8 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
 
     t0 = time.perf_counter()
     ref = Reference(net)
-    checks = check.compare(ref, requests, dep_seed)
+    log(f"reference build: {time.perf_counter() - t0:.2f} s")
+    checks = check.compare(ref, requests, dep_seed, log=log)
     del ref
     log(f"reference check: {time.perf_counter() - t0:.2f} s")
 

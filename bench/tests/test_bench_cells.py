@@ -9,6 +9,7 @@ from pathlib import Path
 import pytest
 
 from bench_testroot import BENCH, REPO, TINY_CELLS, make_root
+from benchkit import parallel
 from benchkit.harness import run_cell
 
 SEED = 2 ** 31 + 977            # larger than 32 signed bits hold
@@ -57,3 +58,16 @@ def test_traced_run_reads_its_per_layer_metrics(root):
     assert "device_idle.serve" not in res["metrics"]
     assert Path(root / "bench" / ".cache" / "trace" / "tiny.serve"
                 / "events.json").is_file()
+
+
+def test_run_logs_the_reference_checks_parts(root, monkeypatch):
+    monkeypatch.setattr(parallel, "worker_count", lambda *a: 2)
+    lines = []
+    res = run_cell(root, "tiny.sim", SEED, 1.0, trace=False,
+                   require_chip=False, log=lines.append)
+    assert res["correct"], res["checks"]
+    text = "\n".join(lines)
+    for part in ("reference build: ", "reference replay: ",
+                 "sessions over 2 workers (os.cpu_count() ",
+                 "process tree peak RSS after the check: "):
+        assert part in text, part

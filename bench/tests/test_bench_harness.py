@@ -266,8 +266,8 @@ def test_run_exits_without_a_result_when_there_is_no_tpu():
 
 
 def test_reference_side_imports_nothing_of_the_program():
-    for name in ("reference", "check", "traffic", "trace", "network",
-                 "work", "stats", "layout", "loadgen"):
+    for name in ("reference", "check", "parallel", "traffic", "trace",
+                 "network", "work", "stats", "layout", "loadgen"):
         text = (BENCH / "benchkit" / f"{name}.py").read_text()
         assert "repro" not in text, name
     for path in list((BENCH / "networks").glob("*.py")) \
