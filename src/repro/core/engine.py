@@ -13,9 +13,10 @@ Two interchangeable execution paths, bit-exact against each other:
 
   * vectorized (default) — the pointer dicts are lowered once to dense
     arrays (`HBMImage.flatten`) and both phases run inside a single
-    jit-compiled step (`kernels/route.py`): the active sources' row
-    spans scatter-added where that reads less than a dense pass
-    (`route.choose_accumulate`), else gathers over a fan-in transpose;
+    jit-compiled step (`kernels/route.py`): phase 2 is the active
+    sources' row spans scatter-added, one integer matrix product over
+    the sources' counts, or gathers over a fan-in transpose, whichever
+    reads least for the table's shapes (`route.choose_accumulate`);
     `run(schedule)` folds T timesteps into one `lax.scan` dispatch and
     `run_batch(schedules)` vmaps that scan over B independent samples
     (per-sample PRNG stream = fold_in(key, sample), fresh V = 0).
@@ -85,7 +86,7 @@ class EventEngine:
         self._tables = None
         # the phase-2 formulation, chosen from the table's shapes when
         # the tables are built (`route.choose_accumulate`): "push",
-        # "fanin" or "csr"
+        # "dense", "fanin" or "csr"
         self._acc_mode = None
         # push-accumulate chunks run: in the last dispatch, and summed
         # over every dispatch
@@ -99,13 +100,15 @@ class EventEngine:
         self._jit_run_lanes = jax.jit(self._run_lanes_impl)
 
     def _build_tables(self):
-        # the push path where it is the cheaper at a sparse firing rate;
-        # of the dense paths, hub topologies fall back from the padded
-        # fan-in transpose to the post-sorted CSR (linear in synapses)
+        # the push path where it is the cheaper at a sparse firing rate,
+        # the dense matrix where it is small; hub topologies fall back
+        # from the padded fan-in transpose to the post-sorted CSR
+        # (linear in synapses)
         self._acc_mode = route_k.choose_accumulate(self.flat, self.n)
         self._tables = route_k.RouteTables.from_flat(
             self.flat, self.n, build_fanin=self._acc_mode == "fanin",
-            build_push=self._acc_mode == "push")
+            build_push=self._acc_mode == "push",
+            build_dense=self._acc_mode == "dense")
 
     @property
     def accumulate_mode(self) -> str:

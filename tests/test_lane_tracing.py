@@ -64,12 +64,13 @@ def scope_segments(text):
             for seg in path.split("/")}
 
 
-@pytest.mark.parametrize("mode", ["fanin", "csr", "push"])
+@pytest.mark.parametrize("mode", ["fanin", "csr", "push", "dense"])
 def test_lane_step_hlo_names_its_five_phases(mode):
     eng = deploy(noisy_compiled(), seed=0).impl
-    assert eng._acc_mode == "fanin"
+    assert eng._acc_mode == "dense"
     eng._tables = route_k.RouteTables.from_flat(eng.flat, eng.n,
-                                                build_push=True)
+                                                build_push=True,
+                                                build_dense=True)
     eng._acc_mode = mode
     B, T = 2, 3
     args = (jnp.zeros((B, eng.n), jnp.int32),
@@ -219,11 +220,11 @@ def test_run_lanes_span_names_its_accumulate_and_chunks(monkeypatch):
     assert k < active.max() and want > T // 2
     assert want < int((-(-active // k)).sum())
 
-    fanin = deploy(c, seed=5)
-    fanin.tracer = Tracer()
-    fanin.run_lanes([-1] * B, w, seeds=[1, 2, 3])
-    (root,) = [s for s in fanin.tracer.spans() if s.name == "run_lanes"]
-    assert (root.attrs["accumulate"], root.attrs["chunks"]) == ("fanin", 0)
+    dense = deploy(c, seed=5)
+    dense.tracer = Tracer()
+    dense.run_lanes([-1] * B, w, seeds=[1, 2, 3])
+    (root,) = [s for s in dense.tracer.spans() if s.name == "run_lanes"]
+    assert (root.attrs["accumulate"], root.attrs["chunks"]) == ("dense", 0)
 
 
 def test_run_lanes_annotation_carries_its_span_start(tmp_path):
